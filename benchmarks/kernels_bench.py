@@ -13,6 +13,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+TARGET_KIND = "TPU v5 lite"  # device the traffic model is priced against
+
 
 def _timeit(fn, *args, reps=5):
     fn(*args)[0].block_until_ready() if isinstance(fn(*args), tuple) else None
@@ -177,7 +179,7 @@ def scan_roofline_rows():
     """
     from repro.aqp.executor import eval_partials
     from repro.kernels import SCAN_TILE_Q, SCAN_TILE_T
-    from repro.launch.roofline import HBM_BW
+    from repro.launch.roofline import peaks
 
     rng = np.random.default_rng(11)
     t_n, q_n, l, c, vmax, m = 65536, 128, 2, 1, 4, 2
@@ -190,7 +192,9 @@ def scan_roofline_rows():
                                            SCAN_TILE_T, SCAN_TILE_Q)
     rows = [
         ("scan/fused_hbm_model_bytes", fused_bytes),
-        ("scan/fused_memory_s_at_hbm_peak", fused_bytes / HBM_BW),
+        # Modelled for the v5e target, not measured on this host.
+        ("scan/fused_memory_s_at_hbm_peak",
+         fused_bytes / peaks(TARGET_KIND)["hbm_bytes_per_s"]),
     ]
     ca = jax.jit(eval_partials).lower(num, cat, meas, snips) \
         .compile().cost_analysis()

@@ -18,6 +18,9 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (batch_bench, cache_bench, improve_bench,
                             kernels_bench, paper_tables, roofline_report,
                             serving_bench, shard_bench)

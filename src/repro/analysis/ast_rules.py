@@ -80,6 +80,9 @@ IDLE_KEEP = {
     "repro.launch.hlo_analysis": "HLO cost extraction behind "
                                  "benchmarks/roofline_report.py",
     "repro.launch.mesh": "mesh topology helpers for the launch cells",
+    "repro.utils.compile_cache": "compile-cache helper that only entry "
+                                 "points call (chip_smoke.py, "
+                                 "benchmarks/run.py), never library code",
     "repro.distributed.compression": "gradient/state compression for the "
                                      "elastic trainer; tests/test_ft.py",
     "repro.ft.elastic": "elastic re-sharding restore path; "

@@ -32,6 +32,7 @@ import dataclasses
 from typing import Callable, Dict, List, Optional
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
@@ -92,10 +93,10 @@ class Program:
     # no-(T, Q)-buffer rule measures "escaped to HBM" against.
     t: int = REP_T
     q: int = REP_Q
-    _jaxpr: Optional[jax.core.ClosedJaxpr] = None
+    _jaxpr: Optional[jex_core.ClosedJaxpr] = None
     _stablehlo: Optional[str] = None
 
-    def jaxpr(self) -> jax.core.ClosedJaxpr:
+    def jaxpr(self) -> jex_core.ClosedJaxpr:
         if self._jaxpr is None:
             self._jaxpr = jax.make_jaxpr(self.fn)(*self.args)
         return self._jaxpr

@@ -232,13 +232,12 @@ def _sharded_mask_fn(mesh, axis: str):
     """Jitted shard_map mask builder, cached per (mesh, axis) so repeated
     block evals reuse one compiled program per shape bucket instead of
     re-tracing the shard_map every call."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     key = (mesh, axis)
     fn = _SHARDED_MASK_FNS.get(key)
     if fn is None:
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             _mask_rows,
             mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P()),
@@ -288,7 +287,7 @@ def eval_partials_sharded(mesh, axis: str, num_normalized, cat, measures,
     # whole padding rows; rows invalidated by a caller-supplied mask are
     # already exactly 0.0 columns inside ``mask``. A single-device mask
     # keeps GSPMD from re-partitioning the reduction.)
-    mask = jax.device_put(mask[:t], jax.devices()[0])
+    mask = jax.device_put(mask[:t], mesh.devices.flat[0])
     if agg_fn is not None:
         return agg_fn(mask, measures, snippets, scanned)
     return _partials_from_mask(mask, measures, snippets, scanned)
@@ -302,12 +301,8 @@ def _kernel_agg_for(local_eval):
     build stays shard_map'd, and the post-gather fold runs through the
     aggregation-only Pallas kernel instead of silently falling back to jnp.
     """
-    if local_eval is None or local_eval is eval_partials:
-        return None
-    try:
-        from repro.kernels.fused_masked_scan import ops as fms_ops
-    except Exception:  # pragma: no cover - pallas unavailable
-        return None
+    from repro.kernels.fused_masked_scan import ops as fms_ops
+
     if local_eval is fms_ops.eval_partials_fused:
         return fms_ops.masked_partials_fused
     return None
@@ -315,20 +310,15 @@ def _kernel_agg_for(local_eval):
 
 def _evaluator_name(local_eval) -> str:
     """Stable name of a per-block evaluator for placement telemetry."""
+    from repro.kernels.fused_masked_scan import ops as fms_ops
+    from repro.kernels.range_mask_agg import ops as rma_ops
+
     if local_eval is None or local_eval is eval_partials:
         return "oracle"
-    try:
-        from repro.kernels.fused_masked_scan import ops as fms_ops
-        if local_eval is fms_ops.eval_partials_fused:
-            return "fused_masked_scan"
-    except Exception:  # pragma: no cover - pallas unavailable
-        pass
-    try:
-        from repro.kernels.range_mask_agg import ops as rma_ops
-        if local_eval is rma_ops.eval_partials_kernel:
-            return "range_mask_agg"
-    except Exception:  # pragma: no cover - pallas unavailable
-        pass
+    if local_eval is fms_ops.eval_partials_fused:
+        return "fused_masked_scan"
+    if local_eval is rma_ops.eval_partials_kernel:
+        return "range_mask_agg"
     return getattr(local_eval, "__name__", "custom")
 
 

@@ -422,6 +422,10 @@ def replay_rounds(
                 f"deadline expired after {used} of {max_batches} batches"
             )
         if final:
+            # Read before record: the router keys what it learns by the
+            # occupancy this answer was served from.
+            occupancy = (intel.occupancy(engine, lp)
+                         if intel is not None else None)
             if cfg.learning:
                 engine.store.record(lp.plan.snippets, raw)
             if intel is not None:
@@ -430,7 +434,7 @@ def replay_rounds(
                 # includes this answer's own ingest — an exact repeat is
                 # fresh, not self-stale.
                 intel.observe(engine, lp, res, target_rel_error,
-                              max_batches, route)
+                              max_batches, route, occupancy)
         yield res, final
         if final:
             return

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import atexit
 import collections
+import contextlib
 import threading
 import weakref
 from typing import Optional
@@ -68,6 +69,23 @@ JITTER = 1e-10
 # Smallest serve-path tiles: fills/batches below these share one program.
 MIN_FILL_BUCKET = 8
 MIN_Q_BUCKET = 8
+
+
+def model_device(device=None):
+    """The CPU device that runs a synopsis' f64 model math, or None.
+
+    The model math is the SE covariance build (``covariance``), the blocked
+    inverse updates (``inv_append_block``'s ``eigh``,
+    ``inv_delete_block``'s ``solve``), the Cholesky refactor and the refit.
+    It stays f64 (see ``repro.core.types``). XLA:TPU takes 67 s to compile
+    the f64 covariance for one (64, 512) shape and 137 s for the improve
+    inputs at (128, 2048), and it refuses f64 ``solve`` (LU is f32-only).
+    So when the serving device is not a CPU, this math runs on the host
+    CPU device, in f64: the same programs, the same bits as the CPU build.
+    None means the serving device is a CPU and nothing moves.
+    """
+    platform = device.platform if device is not None else jax.default_backend()
+    return None if platform == "cpu" else jax.devices("cpu")[0]
 
 
 def inv_append_block(ainv, cols, block, jitter=JITTER):
@@ -309,6 +327,7 @@ class Synopsis:
         self.async_ingest = bool(async_ingest)
         self.max_pending = int(max_pending)
         self.device = device
+        self._host = model_device(device)
         self.min_fill_bucket = int(min_fill_bucket)
         self.min_q_bucket = int(min_q_bucket)
         self.name: Optional[str] = None  # store-assigned state_key (fault key)
@@ -341,8 +360,8 @@ class Synopsis:
         self._keys: dict = {}
         self.params = params or GPParams.init(schema)
         self._sigma = np.zeros((C, C))
-        self._sigma_inv = self._put(jnp.zeros((0, 0)))
-        self._alpha = self._put(jnp.zeros((0,)))
+        self._sigma_inv = self._model_put(jnp.zeros((0, 0)))
+        self._alpha = self._model_put(jnp.zeros((0,)))
         self._updates_since_refactor = 0
         self._order: list = []  # row ids in Sigma^{-1} ordering
         self._device_states: dict = {}  # fill bucket -> padded serve buffers
@@ -360,6 +379,19 @@ class Synopsis:
         if self.device is None:
             return jax.tree.map(jnp.asarray, x)
         return jax.device_put(x, self.device)
+
+    def _model_put(self, x):
+        """Commit model state (Sigma^{-1}, alpha) to where the model math
+        runs: the host CPU device off-CPU (``model_device``), else ``_put``."""
+        if self._host is None:
+            return self._put(x)
+        return jax.device_put(x, self._host)
+
+    def _on_host(self):
+        """Scope in which the f64 model math runs (``model_device``)."""
+        if self._host is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self._host)
 
     # ---------------------------------------------------------------- storage
     def _row_batch(self, rows) -> SnippetBatch:
@@ -444,7 +476,8 @@ class Synopsis:
                 self._unapplied.append(item)
                 return
         try:
-            self._apply_add(*item)
+            with self._on_host():
+                self._apply_add(*item)
         except BaseException as e:  # noqa: BLE001 — quarantine, never raise
             self._mark_quarantined(e, item)
 
@@ -505,7 +538,8 @@ class Synopsis:
             return False
         for i, item in enumerate(parked):
             try:
-                self._apply_add(*item)
+                with self._on_host():
+                    self._apply_add(*item)
             except BaseException as e:  # noqa: BLE001 — re-quarantine
                 with self._qlock:
                     self._quarantine_exc = e
@@ -688,10 +722,10 @@ class Synopsis:
         """Full O(n^3) rebuild of Sigma^{-1} from Sigma (numerical hygiene)."""
         rows = np.asarray(self._order, dtype=np.int64)
         if len(rows) == 0:
-            self._sigma_inv = self._put(jnp.zeros((0, 0)))
+            self._sigma_inv = self._model_put(jnp.zeros((0, 0)))
             self._updates_since_refactor = 0
             return
-        sig = self._put(self._sigma[np.ix_(rows, rows)])
+        sig = self._model_put(self._sigma[np.ix_(rows, rows)])
         chol = inference.factorize(sig, JITTER)
         self._sigma_inv = inference.inverse_from_chol(chol)
         self._updates_since_refactor = 0
@@ -699,7 +733,7 @@ class Synopsis:
     def _refresh_alpha(self):
         rows = np.asarray(self._order, dtype=np.int64)
         if len(rows) == 0:
-            self._alpha = self._put(jnp.zeros((0,)))
+            self._alpha = self._model_put(jnp.zeros((0,)))
             return
         batch = self._row_batch(rows)
         resid = jnp.asarray(self._theta[rows]) - covariance.prior_mean(batch, self.params)
@@ -716,12 +750,14 @@ class Synopsis:
         if self.quarantined or self.n < 3:
             return self.params
         rows = np.asarray(self._order, dtype=np.int64)
-        batch = self._row_batch(rows)
-        theta = jnp.asarray(self._theta[rows])
-        beta2 = jnp.asarray(self._beta2[rows])
-        self.params, _ = learning.fit(
-            batch, theta, beta2, self.schema, steps=steps, lr=lr, learn_sigma=learn_sigma
-        )
+        with self._on_host():
+            batch = self._row_batch(rows)
+            theta = jnp.asarray(self._theta[rows])
+            beta2 = jnp.asarray(self._beta2[rows])
+            self.params, _ = learning.fit(
+                batch, theta, beta2, self.schema, steps=steps, lr=lr,
+                learn_sigma=learn_sigma,
+            )
         self.rebuild()
         self.generation += 1  # relearned params change improved answers
         return self.params
@@ -730,15 +766,17 @@ class Synopsis:
         """Recompute Sigma for the current params, refactor, refresh alpha."""
         self.drain()
         rows = np.asarray(self._order, dtype=np.int64)
-        if len(rows):
-            batch = self._row_batch(rows)
-            sig = np.array(covariance.cov_matrix_jit(batch, batch, self.params))
-            sig[np.diag_indices(len(rows))] = np.asarray(
-                covariance.cov_diag_jit(batch, self.params)
-            ) + self._beta2[rows]
-            self._sigma[np.ix_(rows, rows)] = sig
-        self._refactor()
-        self._refresh_alpha()
+        with self._on_host():
+            if len(rows):
+                batch = self._row_batch(rows)
+                sig = np.array(
+                    covariance.cov_matrix_jit(batch, batch, self.params))
+                sig[np.diag_indices(len(rows))] = np.asarray(
+                    covariance.cov_diag_jit(batch, self.params)
+                ) + self._beta2[rows]
+                self._sigma[np.ix_(rows, rows)] = sig
+            self._refactor()
+            self._refresh_alpha()
         self._device_states.clear()
 
     # ------------------------------------------------------------------ serve
@@ -799,9 +837,15 @@ class Synopsis:
         if use_kernel:
             from repro.kernels.gp_batch_infer import ops as gp_ops
 
-            k_mat, kappa2, mu_new = _improve_inputs_jit(
-                past, valid, self.params, padded_new
-            )
+            # The f64 covariance inputs are model math (``model_device``);
+            # the kernel and the validation run on the serving device.
+            inputs = (past, valid, self.params, padded_new)
+            if self._host is not None:
+                inputs = jax.device_put(inputs, self._host)
+            k_mat, kappa2, mu_new = _improve_inputs_jit(*inputs)
+            if self._host is not None:
+                k_mat, kappa2, mu_new = jax.device_put(
+                    (k_mat, kappa2, mu_new), self.device or jax.devices()[0])
             m_theta, m_beta2, _ = gp_ops.gp_batch_infer(
                 k_mat, sinv, alpha, kappa2, mu_new, raw_theta, raw_beta2
             )
@@ -827,13 +871,14 @@ class Synopsis:
         if self.n == 0:
             return
         rows = np.arange(self.n)
-        theta, beta2 = adjust_answers(
-            jnp.asarray(self._theta[rows]),
-            jnp.asarray(self._beta2[rows]),
-            jnp.asarray(self._measure[rows]),
-            jnp.asarray(self._agg[rows]),
-            stats,
-        )
+        with self._on_host():
+            theta, beta2 = adjust_answers(
+                jnp.asarray(self._theta[rows]),
+                jnp.asarray(self._beta2[rows]),
+                jnp.asarray(self._measure[rows]),
+                jnp.asarray(self._agg[rows]),
+                stats,
+            )
         self._theta[rows] = np.asarray(theta)
         self._beta2[rows] = np.asarray(beta2)
         self.rebuild()

@@ -130,16 +130,26 @@ class WorkloadIntel:
         return self.router.choose_route(engine, lp, target_rel_error,
                                         max_batches)
 
+    def occupancy(self, engine, lp):
+        """The store occupancy ``lp`` is served from (see
+        ``ServeRouter.occupancy``); None for queries the router never
+        observes."""
+        if not lp.supported or lp.plan is None:
+            return None
+        return self.router.occupancy(engine, lp)
+
     def observe(self, engine, lp, res, target_rel_error: Optional[float],
-                max_batches: int, route: str):
+                max_batches: int, route: str, occupancy):
         """Final-round bookkeeping for one executed query: route counters,
         router statistics (and the periodic ladder application), and the
         answer-cache insert. Runs right after ``store.record`` in the plan
         lifecycle, so the generation snapshot includes the answer's own
-        ingest bump."""
+        ingest bump; ``occupancy`` (``self.occupancy``) was read before
+        that record."""
         self.telemetry.record_route(route)
         if lp.supported:
-            self.router.observe(engine, lp, res, target_rel_error, route)
+            self.router.observe(engine, lp, res, target_rel_error, route,
+                                occupancy)
             sig = QuerySignature.from_query(engine.schema, lp.query)
             if sig is not None:
                 self.cache.insert(engine.store, sig, lp, res,

@@ -43,7 +43,7 @@ every product), so ladder moves change compile/cost, never answers.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from repro.core.store import group_rows
 from repro.core.types import SNIPPET_TILE, bucket_size
@@ -75,7 +75,23 @@ class ServeRouter:
 
     # ------------------------------------------------------------ features
     @staticmethod
-    def _features(engine, lp) -> Tuple[int, float, float]:
+    def occupancy(engine, lp) -> List[Tuple[int, int, int]]:
+        """``(q rows, fill, fill bucket)`` per aggregate key of ``lp``.
+
+        Each synopsis is drained first, so the fill read here is the one
+        the improve path serves from — never a race with the background
+        ingest thread (A007: route features are deterministic)."""
+        out = []
+        for key, rows in group_rows(lp.plan.snippets):
+            syn = engine.store.get(key)
+            if syn is not None:
+                syn.drain()
+            n = syn.n if syn is not None else 0
+            out.append((len(rows), n, syn._fill_bucket() if n else 0))
+        return out
+
+    @classmethod
+    def _features(cls, engine, lp) -> Tuple[int, float, float]:
         """(max fill bucket, batch_cost, improve_cost) — all deterministic
         functions of plan + store occupancy (A007)."""
         tuples_per_batch = (
@@ -86,10 +102,8 @@ class ServeRouter:
         batch_cost = tuples_per_batch * n_pad
         improve_cost = 0.0
         fill_bucket = 0
-        for key, rows in group_rows(lp.plan.snippets):
-            syn = engine.store.get(key)
-            fb = syn._fill_bucket() if syn is not None and syn.n else 0
-            qb = bucket_size(len(rows), engine.config.min_q_bucket)
+        for q, _, fb in cls.occupancy(engine, lp):
+            qb = bucket_size(q, engine.config.min_q_bucket)
             improve_cost += qb * fb * fb + fb * fb
             fill_bucket = max(fill_bucket, fb)
         return fill_bucket, batch_cost, improve_cost
@@ -133,17 +147,17 @@ class ServeRouter:
         return route
 
     # ------------------------------------------------------------- observe
-    def observe(self, engine, lp, res, target: Optional[float], route: str):
+    def observe(self, engine, lp, res, target: Optional[float], route: str,
+                occupancy):
+        """Record one executed query. ``occupancy`` is ``occupancy(engine,
+        lp)`` taken before the query recorded its own answers: E[batches]
+        is keyed by the fill the query was served from."""
         if lp.plan is None:
             return
         fill_bucket = 0
-        for key, rows in group_rows(lp.plan.snippets):
-            q = len(rows)
+        for q, n, fb in occupancy:
             self._q_hist[q] = self._q_hist.get(q, 0) + 1
-            syn = engine.store.get(key)
-            n = syn.n if syn is not None else 0
             self._fill_hist[n] = self._fill_hist.get(n, 0) + 1
-            fb = syn._fill_bucket() if syn is not None and syn.n else 0
             fill_bucket = max(fill_bucket, fb)
         if target is not None and route == "improve":
             stat = self._batches.setdefault(fill_bucket, [0, 0.0])

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import RANGE_EPS
+from repro.kernels import RANGE_EPS, block_spec
 
 
 def _range_cat_mask(x, codes, lo_ref, hi_ref, cat_ref, valid_ref,
@@ -71,6 +71,17 @@ def _range_cat_mask(x, codes, lo_ref, hi_ref, cat_ref, valid_ref,
     return m * valid_ref[...]  # (TT, TQ) * (TT, 1)
 
 
+def _fold_dot(mask, payload, dt):
+    """mask^T @ payload for one (TT, TQ) x (TT, P) tile. HIGHEST precision:
+    the TPU MXU would otherwise round the f32 measures to bf16."""
+    return jax.lax.dot_general(
+        mask, payload,
+        dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=dt,
+    )
+
+
 def _accumulate(acc, out_ref, t):
     @pl.when(t == 0)
     def _init():
@@ -86,17 +97,12 @@ def _fms_kernel(x_ref, codes_ref, valid_ref, payload_ref, lo_ref, hi_ref,
     t = pl.program_id(1)
     m = _range_cat_mask(x_ref[...], codes_ref[...], lo_ref, hi_ref, cat_ref,
                         valid_ref, n_dims=n_dims, n_cat=n_cat, vmax=vmax)
-    acc = jax.lax.dot_general(
-        m, payload_ref[...],
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    )  # (TQ, P)
+    acc = _fold_dot(m, payload_ref[...], out_ref.dtype)  # (TQ, P)
     _accumulate(acc, out_ref, t)
 
 
 def fused_masked_scan_pallas(x, codes, valid, payload, lo, hi, cat,
-                             *, tile_t: int, tile_q: int,
-                             interpret: bool = True):
+                             *, tile_t: int, tile_q: int, interpret: bool):
     """Raw pallas_call; T and Q must be pre-padded to tile multiples.
 
     x: (T, L) normalized numerics; codes: (T, C) int32 category codes
@@ -116,15 +122,15 @@ def fused_masked_scan_pallas(x, codes, valid, payload, lo, hi, cat,
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_t, l), lambda q, t: (t, 0)),  # x
-            pl.BlockSpec((tile_t, c), lambda q, t: (t, 0)),  # codes
-            pl.BlockSpec((tile_t, 1), lambda q, t: (t, 0)),  # valid
-            pl.BlockSpec((tile_t, p), lambda q, t: (t, 0)),  # payload
-            pl.BlockSpec((tile_q, l), lambda q, t: (q, 0)),  # lo
-            pl.BlockSpec((tile_q, l), lambda q, t: (q, 0)),  # hi
-            pl.BlockSpec((tile_q, cat.shape[1]), lambda q, t: (q, 0)),  # cat
+            block_spec((tile_t, l), lambda q, t: (t, 0)),  # x
+            block_spec((tile_t, c), lambda q, t: (t, 0)),  # codes
+            block_spec((tile_t, 1), lambda q, t: (t, 0)),  # valid
+            block_spec((tile_t, p), lambda q, t: (t, 0)),  # payload
+            block_spec((tile_q, l), lambda q, t: (q, 0)),  # lo
+            block_spec((tile_q, l), lambda q, t: (q, 0)),  # hi
+            block_spec((tile_q, cat.shape[1]), lambda q, t: (q, 0)),  # cat
         ],
-        out_specs=pl.BlockSpec((tile_q, p), lambda q, t: (q, 0)),
+        out_specs=block_spec((tile_q, p), lambda q, t: (q, 0)),
         out_shape=jax.ShapeDtypeStruct((q_n, p), payload.dtype),
         interpret=interpret,
     )(x, codes, valid, payload, lo, hi, cat)
@@ -132,16 +138,12 @@ def fused_masked_scan_pallas(x, codes, valid, payload, lo, hi, cat,
 
 def _mpa_kernel(mask_ref, payload_ref, out_ref):
     t = pl.program_id(1)
-    acc = jax.lax.dot_general(
-        mask_ref[...], payload_ref[...],
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    )
+    acc = _fold_dot(mask_ref[...], payload_ref[...], out_ref.dtype)
     _accumulate(acc, out_ref, t)
 
 
 def masked_partials_pallas(mask, payload, *, tile_t: int, tile_q: int,
-                           interpret: bool = True):
+                           interpret: bool):
     """Aggregation-only entry: a pre-built (T, Q) mask (e.g. gathered from a
     sharded mask build) folded against the payload in the SAME fixed tile
     order as the fused kernel — the mesh-composition path of the scan."""
@@ -153,10 +155,10 @@ def masked_partials_pallas(mask, payload, *, tile_t: int, tile_q: int,
         _mpa_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_t, tile_q), lambda q, t: (t, q)),  # mask
-            pl.BlockSpec((tile_t, p), lambda q, t: (t, 0)),  # payload
+            block_spec((tile_t, tile_q), lambda q, t: (t, q)),  # mask
+            block_spec((tile_t, p), lambda q, t: (t, 0)),  # payload
         ],
-        out_specs=pl.BlockSpec((tile_q, p), lambda q, t: (q, 0)),
+        out_specs=block_spec((tile_q, p), lambda q, t: (q, 0)),
         out_shape=jax.ShapeDtypeStruct((q_n, p), payload.dtype),
         interpret=interpret,
     )(mask, payload)

@@ -11,10 +11,10 @@ fold (``masked_tile_fold``), which ``_partials_from_mask`` also performs.
 sharded over the mesh, gathered, and reduced here through the kernel — the
 composition that makes ``use_kernels=True`` meaningful under a mesh.
 
-Dtype policy: interpret mode (CPU container) runs f64 end to end — that is
-the configuration the bitwise gate pins.  With ``interpret=False`` (real
-TPU) inputs are cast to f32 (the MXU has no f64 path) and parity degrades
-to allclose; see ``repro.kernels`` docstring.
+Dtype policy: interpret mode (operands on the CPU) runs f64 end to end —
+that is the configuration the bitwise gate pins.  Compiled on a TPU, inputs
+are cast to f32 (the MXU has no f64 path) and parity degrades to an f32
+tolerance; see ``repro.kernels`` docstring.
 """
 from __future__ import annotations
 
@@ -23,7 +23,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET, SCAN_TILE_Q, SCAN_TILE_T
+from repro.kernels import SCAN_TILE_Q, SCAN_TILE_T, interpret_for
 from repro.kernels.fused_masked_scan.kernel import (
     fused_masked_scan_pallas,
     masked_partials_pallas,
@@ -58,10 +58,8 @@ def _epilogue(out, snippets, m, scanned):
     return Partials(sums, sumsq, out[:, 2 * m], scanned)
 
 
-@partial(jax.jit, static_argnames=("tile_t", "tile_q", "interpret"))
 def eval_partials_fused(num_normalized, cat, measures, snippets, valid=None,
-                        *, tile_t: int = SCAN_TILE_T, tile_q: int = TILE_Q,
-                        interpret: bool = INTERPRET):
+                        *, tile_t: int = SCAN_TILE_T, tile_q: int = TILE_Q):
     """Fused-kernel partials for one tuple block (drop-in for
     ``eval_partials``; bitwise-equal to it in interpret mode).
 
@@ -69,6 +67,14 @@ def eval_partials_fused(num_normalized, cat, measures, snippets, valid=None,
     invalid rows contribute exactly nothing and ``scanned`` is the mask sum
     (the TRUE tuple count), matching the oracle's contract exactly.
     """
+    return _eval_partials_fused(
+        num_normalized, cat, measures, snippets, valid, tile_t=tile_t,
+        tile_q=tile_q, interpret=interpret_for(num_normalized))
+
+
+@partial(jax.jit, static_argnames=("tile_t", "tile_q", "interpret"))
+def _eval_partials_fused(num_normalized, cat, measures, snippets, valid,
+                         *, tile_t: int, tile_q: int, interpret: bool):
     dt = jnp.float64 if interpret else jnp.float32
     t_n, m = measures.shape
     q_n = snippets.lo.shape[0]
@@ -101,13 +107,19 @@ def eval_partials_fused(num_normalized, cat, measures, snippets, valid=None,
     return _epilogue(out, snippets, m, scanned)
 
 
-@partial(jax.jit, static_argnames=("tile_t", "tile_q", "interpret"))
 def masked_partials_fused(mask, measures, snippets, scanned,
-                          *, tile_t: int = SCAN_TILE_T, tile_q: int = TILE_Q,
-                          interpret: bool = INTERPRET):
+                          *, tile_t: int = SCAN_TILE_T, tile_q: int = TILE_Q):
     """Kernel-backed drop-in for ``_partials_from_mask``: fold a pre-built
     (T, Q) predicate mask (e.g. gathered from the sharded mask build)
     against [measures, measures^2, 1] in the canonical tile order."""
+    return _masked_partials_fused(mask, measures, snippets, scanned,
+                                  tile_t=tile_t, tile_q=tile_q,
+                                  interpret=interpret_for(mask))
+
+
+@partial(jax.jit, static_argnames=("tile_t", "tile_q", "interpret"))
+def _masked_partials_fused(mask, measures, snippets, scanned,
+                           *, tile_t: int, tile_q: int, interpret: bool):
     dt = jnp.float64 if interpret else jnp.float32
     t_n, m = measures.shape
     q_n = mask.shape[1]

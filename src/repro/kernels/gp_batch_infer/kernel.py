@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import block_spec
+
 GAMMA_FLOOR = 1e-30
 
 
@@ -40,13 +42,16 @@ def _gp_kernel(k1_ref, k2_ref, sinv_ref, alpha_ref, kappa2_ref, mu_ref,
     p = jax.lax.dot_general(
         k1_ref[...], sinv_ref[...],
         dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )  # (TQ, TC2)
-    gacc[...] = gacc[...] + jnp.sum(p * k2_ref[...], axis=1)
+    gacc[...] = gacc[...] + jnp.sum(p * k2_ref[...], axis=1, keepdims=True)
 
     @pl.when(c2 == 0)
     def _theta_acc():
-        tacc[...] = tacc[...] + k1_ref[...] @ alpha_ref[...]
+        # k1 @ alpha as a lane reduction: alpha is a (1, TC) row.
+        tacc[...] = tacc[...] + jnp.sum(k1_ref[...] * alpha_ref[...], axis=1,
+                                        keepdims=True)
 
     @pl.when((c1 == nc1 - 1) & (c2 == nc2 - 1))
     def _finalize():
@@ -65,38 +70,36 @@ def _gp_kernel(k1_ref, k2_ref, sinv_ref, alpha_ref, kappa2_ref, mu_ref,
 
 def gp_batch_infer_pallas(k_mat, sigma_inv, alpha, kappa2, mu_new, raw_theta,
                           raw_beta2, *, tile_q: int = 128, tile_c: int = 128,
-                          interpret: bool = True):
-    """Raw pallas_call; Q and C must be pre-padded to tile multiples."""
+                          interpret: bool):
+    """Raw pallas_call; Q and C must be pre-padded to tile multiples.
+
+    Per-snippet vectors are (Q, 1) columns and ``alpha`` a (1, C) row:
+    Mosaic tiles a 1-D f32 operand at 1024 lanes, so a 1-D block of 128
+    does not match its layout.
+    """
     q_n, c_n = k_mat.shape
     assert q_n % tile_q == 0 and c_n % tile_c == 0
     grid = (q_n // tile_q, c_n // tile_c, c_n // tile_c)
     dt = k_mat.dtype
+    col = block_spec((tile_q, 1), lambda q, c1, c2: (q, 0))
     return pl.pallas_call(
         _gp_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_q, tile_c), lambda q, c1, c2: (q, c1)),  # K (c1)
-            pl.BlockSpec((tile_q, tile_c), lambda q, c1, c2: (q, c2)),  # K (c2)
-            pl.BlockSpec((tile_c, tile_c), lambda q, c1, c2: (c1, c2)),  # Sinv
-            pl.BlockSpec((tile_c,), lambda q, c1, c2: (c1,)),  # alpha
-            pl.BlockSpec((tile_q,), lambda q, c1, c2: (q,)),  # kappa2
-            pl.BlockSpec((tile_q,), lambda q, c1, c2: (q,)),  # mu
-            pl.BlockSpec((tile_q,), lambda q, c1, c2: (q,)),  # raw theta
-            pl.BlockSpec((tile_q,), lambda q, c1, c2: (q,)),  # raw beta2
+            block_spec((tile_q, tile_c), lambda q, c1, c2: (q, c1)),  # K (c1)
+            block_spec((tile_q, tile_c), lambda q, c1, c2: (q, c2)),  # K (c2)
+            block_spec((tile_c, tile_c), lambda q, c1, c2: (c1, c2)),  # Sinv
+            block_spec((1, tile_c), lambda q, c1, c2: (0, c1)),  # alpha
+            col,  # kappa2
+            col,  # mu
+            col,  # raw theta
+            col,  # raw beta2
         ],
-        out_specs=[
-            pl.BlockSpec((tile_q,), lambda q, c1, c2: (q,)),
-            pl.BlockSpec((tile_q,), lambda q, c1, c2: (q,)),
-            pl.BlockSpec((tile_q,), lambda q, c1, c2: (q,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((q_n,), dt),
-            jax.ShapeDtypeStruct((q_n,), dt),
-            jax.ShapeDtypeStruct((q_n,), dt),
-        ],
+        out_specs=[col, col, col],
+        out_shape=[jax.ShapeDtypeStruct((q_n, 1), dt)] * 3,
         scratch_shapes=[
-            pltpu.VMEM((tile_q,), jnp.float32),
-            pltpu.VMEM((tile_q,), jnp.float32),
+            pltpu.VMEM((tile_q, 1), jnp.float32),
+            pltpu.VMEM((tile_q, 1), jnp.float32),
         ],
         interpret=interpret,
     )(k_mat, k_mat, sigma_inv, alpha, kappa2, mu_new, raw_theta, raw_beta2)

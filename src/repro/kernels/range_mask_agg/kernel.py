@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import RANGE_EPS
+from repro.kernels import RANGE_EPS, block_spec
 
 
 def _rma_kernel(x_ref, payload_ref, lo_ref, hi_ref, em_ref, out_ref, *, n_dims: int):
@@ -49,7 +49,7 @@ def _rma_kernel(x_ref, payload_ref, lo_ref, hi_ref, em_ref, out_ref, *, n_dims: 
 
 def range_mask_agg_pallas(x, payload, lo, hi, extra_mask,
                           *, tile_t: int = 512, tile_q: int = 128,
-                          interpret: bool = True):
+                          interpret: bool):
     """Raw pallas_call; T and Q must be pre-padded to tile multiples."""
     t_n, l = x.shape
     q_n = lo.shape[0]
@@ -61,13 +61,13 @@ def range_mask_agg_pallas(x, payload, lo, hi, extra_mask,
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_t, l), lambda q, t: (t, 0)),  # x
-            pl.BlockSpec((tile_t, p), lambda q, t: (t, 0)),  # payload
-            pl.BlockSpec((tile_q, l), lambda q, t: (q, 0)),  # lo
-            pl.BlockSpec((tile_q, l), lambda q, t: (q, 0)),  # hi
-            pl.BlockSpec((tile_t, tile_q), lambda q, t: (t, q)),  # extra mask
+            block_spec((tile_t, l), lambda q, t: (t, 0)),  # x
+            block_spec((tile_t, p), lambda q, t: (t, 0)),  # payload
+            block_spec((tile_q, l), lambda q, t: (q, 0)),  # lo
+            block_spec((tile_q, l), lambda q, t: (q, 0)),  # hi
+            block_spec((tile_t, tile_q), lambda q, t: (t, q)),  # extra mask
         ],
-        out_specs=pl.BlockSpec((tile_q, p), lambda q, t: (q, 0)),
+        out_specs=block_spec((tile_q, p), lambda q, t: (q, 0)),
         out_shape=jax.ShapeDtypeStruct((q_n, p), jnp.float32),
         interpret=interpret,
     )(x, payload, lo, hi, extra_mask)

@@ -6,7 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_for
 from repro.kernels.range_mask_agg.kernel import range_mask_agg_pallas
 
 
@@ -19,11 +19,17 @@ def _pad_axis(x, axis, mult, fill=0.0):
     return jnp.pad(x, widths, constant_values=fill)
 
 
-@partial(jax.jit, static_argnames=("tile_t", "tile_q", "interpret"))
 def range_mask_agg(x, payload, lo, hi, extra_mask=None,
-                   *, tile_t: int = 512, tile_q: int = 128,
-                   interpret: bool = INTERPRET):
-    """out[q, p] = sum over tuples matching snippet q of payload[t, p]."""
+                   *, tile_t: int = 512, tile_q: int = 128):
+    """out[q, p] = sum over tuples matching snippet q of payload[t, p].
+    Compiled on TPU, interpreted elsewhere."""
+    return _range_mask_agg(x, payload, lo, hi, extra_mask, tile_t=tile_t,
+                           tile_q=tile_q, interpret=interpret_for(x))
+
+
+@partial(jax.jit, static_argnames=("tile_t", "tile_q", "interpret"))
+def _range_mask_agg(x, payload, lo, hi, extra_mask,
+                    *, tile_t: int, tile_q: int, interpret: bool):
     t_n, _ = x.shape
     q_n = lo.shape[0]
     dt = jnp.float32

@@ -19,6 +19,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import block_spec
 from jax.scipy.special import erf
 
 SQRT_PI = 1.7724538509055159
@@ -50,7 +52,7 @@ def _se_cov_kernel(lo_i_ref, hi_i_ref, lo_j_ref, hi_j_ref, ls_ref, ni_ref, nj_re
 
 
 def se_cov_pallas(lo_i, hi_i, lo_j, hi_j, ls, sigma2, norm_i, norm_j,
-                  *, tile_i: int = 128, tile_j: int = 128, interpret: bool = True):
+                  *, tile_i: int = 128, tile_j: int = 128, interpret: bool):
     """Raw pallas_call; inputs must be pre-padded to tile multiples (see ops)."""
     n_i, l = lo_i.shape
     n_j = lo_j.shape[0]
@@ -61,16 +63,16 @@ def se_cov_pallas(lo_i, hi_i, lo_j, hi_j, ls, sigma2, norm_i, norm_j,
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_i, l), lambda i, j: (i, 0)),  # lo_i
-            pl.BlockSpec((tile_i, l), lambda i, j: (i, 0)),  # hi_i
-            pl.BlockSpec((tile_j, l), lambda i, j: (j, 0)),  # lo_j
-            pl.BlockSpec((tile_j, l), lambda i, j: (j, 0)),  # hi_j
-            pl.BlockSpec((l,), lambda i, j: (0,)),  # ls
-            pl.BlockSpec((tile_i,), lambda i, j: (i,)),  # norm_i
-            pl.BlockSpec((tile_j,), lambda i, j: (j,)),  # norm_j
-            pl.BlockSpec((1,), lambda i, j: (0,)),  # sigma2
+            block_spec((tile_i, l), lambda i, j: (i, 0)),  # lo_i
+            block_spec((tile_i, l), lambda i, j: (i, 0)),  # hi_i
+            block_spec((tile_j, l), lambda i, j: (j, 0)),  # lo_j
+            block_spec((tile_j, l), lambda i, j: (j, 0)),  # hi_j
+            block_spec((l,), lambda i, j: (0,)),  # ls
+            block_spec((tile_i,), lambda i, j: (i,)),  # norm_i
+            block_spec((tile_j,), lambda i, j: (j,)),  # norm_j
+            block_spec((1,), lambda i, j: (0,)),  # sigma2
         ],
-        out_specs=pl.BlockSpec((tile_i, tile_j), lambda i, j: (i, j)),
+        out_specs=block_spec((tile_i, tile_j), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_i, n_j), lo_i.dtype),
         interpret=interpret,
     )(lo_i, hi_i, lo_j, hi_j, ls, norm_i, norm_j, sigma2)

@@ -6,7 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import INTERPRET
+from repro.kernels import interpret_for
 from repro.kernels.se_covariance.kernel import se_cov_pallas
 
 
@@ -19,17 +19,22 @@ def _pad_rows(x, mult, fill):
     return jnp.concatenate([x, fill_arr], axis=0)
 
 
-@partial(jax.jit, static_argnames=("tile_i", "tile_j", "interpret"))
-def se_cov_matrix(
-    lo_i, hi_i, lo_j, hi_j, ls, sigma2, norm_i, norm_j,
-    *, tile_i: int = 128, tile_j: int = 128, interpret: bool = INTERPRET,
-):
+def se_cov_matrix(lo_i, hi_i, lo_j, hi_j, ls, sigma2, norm_i, norm_j,
+                  *, tile_i: int = 128, tile_j: int = 128):
     """sigma2 * prod_k II_k / (norm_i norm_j) as an (n_i, n_j) matrix.
 
     Pads both snippet batches to tile multiples (padding rows use unit-width
     ranges and norm=1 so they are numerically benign), runs the Pallas kernel,
-    slices the result back.
+    slices the result back. Compiled on TPU, interpreted elsewhere.
     """
+    return _se_cov_matrix(lo_i, hi_i, lo_j, hi_j, ls, sigma2, norm_i, norm_j,
+                          tile_i=tile_i, tile_j=tile_j,
+                          interpret=interpret_for(lo_i))
+
+
+@partial(jax.jit, static_argnames=("tile_i", "tile_j", "interpret"))
+def _se_cov_matrix(lo_i, hi_i, lo_j, hi_j, ls, sigma2, norm_i, norm_j,
+                   *, tile_i: int, tile_j: int, interpret: bool):
     n_i, n_j = lo_i.shape[0], lo_j.shape[0]
     dt = jnp.float32 if lo_i.dtype == jnp.float32 else lo_i.dtype
     args_i = [_pad_rows(x.astype(dt), tile_i, f) for x, f in

@@ -1,8 +1,11 @@
-"""Roofline terms from compiled dry-run artifacts (TPU v5e-class targets).
+"""Roofline terms from compiled dry-run artifacts.
 
-    compute term    = HLO_FLOPs / (chips x 197 TFLOP/s bf16)
-    memory term     = HLO_bytes / (chips x 819 GB/s)
-    collective term = wire_bytes / (chips x 50 GB/s/link)
+    compute term    = HLO_FLOPs / peak FLOP/s
+    memory term     = HLO_bytes / peak HBM bytes/s
+    collective term = wire_bytes / ICI bytes/s per link
+
+with the peaks of the device the terms are for, looked up by its
+``jax.Device.device_kind`` in ``PEAKS`` (a kind not in the table raises).
 
 cost_analysis() is per-device and counts scan bodies once (measured fact,
 DESIGN.md §5), so per-cell totals are assembled as
@@ -17,9 +20,27 @@ from __future__ import annotations
 
 from typing import Dict
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # bytes/s / chip
-ICI_BW = 50e9  # bytes/s / link
+# Published per-chip peaks keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect (4 links of 50 GB/s).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {
+        "flops_bf16": 197e12,
+        "hbm_bytes_per_s": 819e9,
+        "ici_bytes_per_s_per_link": 50e9,
+    },
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The published peaks of ``device_kind``; raises for an unknown kind
+    rather than measuring against another chip's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}") from None
 
 
 def combine_costs(step_cost: Dict, probe_costs) -> Dict[str, float]:
@@ -31,11 +52,13 @@ def combine_costs(step_cost: Dict, probe_costs) -> Dict[str, float]:
     return {"flops_per_device": float(flops), "bytes_per_device": float(byts)}
 
 
-def roofline(flops_per_device: float, bytes_per_device: float,
+def roofline(device_kind: str, flops_per_device: float,
+             bytes_per_device: float,
              wire_bytes_per_device: float) -> Dict[str, float]:
-    compute_s = flops_per_device / PEAK_FLOPS
-    memory_s = bytes_per_device / HBM_BW
-    collective_s = wire_bytes_per_device / ICI_BW
+    peak = peaks(device_kind)
+    compute_s = flops_per_device / peak["flops_bf16"]
+    memory_s = bytes_per_device / peak["hbm_bytes_per_s"]
+    collective_s = wire_bytes_per_device / peak["ici_bytes_per_s_per_link"]
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": collective_s}
     dom = max(terms, key=terms.get)

@@ -46,6 +46,9 @@ class FrontHTTPServer(ThreadingHTTPServer):
 
     daemon_threads = True
     allow_reuse_address = True
+    # socketserver's default listen backlog of 5 resets the connections of
+    # a burst of concurrent clients before the accept loop reaches them.
+    request_queue_size = 128
 
     def __init__(self, address, front):
         self.front = front

@@ -158,14 +158,13 @@ def test_t002_canonical_fold_clean():
 def _psum_mask_build():
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = Mesh(np.array(jax.devices()), ("data",))
 
     def build(x):
-        return shard_map(lambda v: v - jax.lax.psum(v.sum(), "data"),
-                         mesh=mesh, in_specs=(P("data"),),
-                         out_specs=P("data"))(x)
+        return jax.shard_map(lambda v: v - jax.lax.psum(v.sum(), "data"),
+                             mesh=mesh, in_specs=(P("data"),),
+                             out_specs=P("data"))(x)
 
     n = 64 * len(jax.devices())
     return Program("psum_mask_build", jax.jit(build),

@@ -313,3 +313,40 @@ def test_state_dict_returns_copies_not_views():
     syn2 = Synopsis(sch, capacity=4)
     syn2.load_state_dict(snap)
     np.testing.assert_array_equal(np.asarray(syn2.theta()), theta_before)
+
+
+def test_model_math_on_a_host_device_is_answer_invariant(monkeypatch,
+                                                         forced_devices):
+    """Off the CPU the f64 model math runs on the host CPU device
+    (``model_device``) while the improve kernel serves from the serving
+    device. Here a second host device stands in for the host: every
+    transfer that path makes runs, and answers and model state stay
+    bitwise equal to the all-on-one-device engine."""
+    from repro.aqp import workload as W
+    from repro.core import synopsis as synopsis_mod
+    from repro.core.engine import EngineConfig, VerdictEngine
+
+    serve, host = forced_devices(2)
+    rel = W.make_relation(seed=3, n_rows=4000, n_num=2, cat_sizes=(4,),
+                          n_measures=1)
+    qs = W.make_workload(4, rel.schema, 6, agg_kinds=("AVG", "COUNT"))
+    cfg = dict(sample_rate=0.2, n_batches=3, seed=1, use_kernels=True)
+
+    def run():
+        eng = VerdictEngine(rel, EngineConfig(**cfg))
+        cells = [c for q in qs for c in eng.execute(q).cells]
+        eng.refit(steps=3)
+        cells += [c for q in qs for c in eng.execute(q).cells]
+        eng.drain()
+        return eng, cells
+
+    _, want = run()
+    monkeypatch.setattr(synopsis_mod, "model_device",
+                        lambda device=None: host)
+    eng, got = run()
+    assert got == want
+    for key in eng.store.keys():
+        syn = eng.store.get(key)
+        assert syn.n > 0
+        assert syn._sigma_inv.devices() == {host}
+        assert syn._padded_state()[2].devices() == {serve}

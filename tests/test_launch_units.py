@@ -47,12 +47,17 @@ def test_shape_bytes_tuple_and_dtypes():
 
 
 def test_roofline_terms_and_dominance():
-    r = R.roofline(197e12, 819e9 * 2, 50e9 * 0.5)
+    r = R.roofline("TPU v5 lite", 197e12, 819e9 * 2, 50e9 * 0.5)
     assert r["compute_s"] == pytest.approx(1.0)
     assert r["memory_s"] == pytest.approx(2.0)
     assert r["collective_s"] == pytest.approx(0.5)
     assert r["dominant"] == "memory_s"
     assert r["roofline_fraction"] == pytest.approx(0.5)
+
+
+def test_roofline_unknown_device_kind_raises():
+    with pytest.raises(ValueError, match="no published peaks"):
+        R.roofline("cpu", 1.0, 1.0, 1.0)
 
 
 def test_combine_costs():
