@@ -47,6 +47,8 @@ from __future__ import annotations
 import time
 from typing import List, Optional, Sequence
 
+from jax.profiler import TraceAnnotation
+
 from repro.aqp import queries as Q
 from repro.aqp.executor import ScanPlacement, scan_placement
 from repro.aqp.plan import (
@@ -130,18 +132,22 @@ class BatchExecutor:
                     results[i] = served
                 else:
                     live_idx.append(i)
-        wp = plan_workload(eng, [queries[i] for i in live_idx])
+        with TraceAnnotation("verdict.plan"):
+            wp = plan_workload(eng, [queries[i] for i in live_idx])
+            phys_main = PhysicalPlan(eng.batches, wp.fused, self._eval,
+                                     stats=wp.stats)
+            phys_raw = PhysicalPlan(eng.batches, wp.fused_raw, plain_eval,
+                                    stats=wp.stats)
         self.stats = wp.stats
-        phys_main = PhysicalPlan(eng.batches, wp.fused, self._eval,
-                                 stats=wp.stats)
-        phys_raw = PhysicalPlan(eng.batches, wp.fused_raw, plain_eval,
-                                stats=wp.stats)
         for lp in wp.logical:
             deadline = (None if deadline_s is None
                         else time.monotonic() + float(deadline_s))
-            results[live_idx[lp.index]] = replay_query(
-                eng, lp, phys_main if lp.supported else phys_raw,
-                target_rel_error=target_rel_error, max_batches=max_batches,
-                stop_delta=stop_delta, deadline=deadline,
-            )
+            q = live_idx[lp.index]
+            with TraceAnnotation("verdict.replay", q=q):
+                results[q] = replay_query(
+                    eng, lp, phys_main if lp.supported else phys_raw,
+                    target_rel_error=target_rel_error,
+                    max_batches=max_batches, stop_delta=stop_delta,
+                    deadline=deadline,
+                )
         return results

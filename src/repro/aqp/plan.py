@@ -40,6 +40,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.aqp import queries as Q
 from repro.aqp.executor import Partials, estimates_from_partials, eval_partials
@@ -274,19 +275,23 @@ class PhysicalPlan:
         (``None``: every non-padding snippet, in interning order)."""
         while len(self._snapshots) <= b:
             i = len(self._snapshots)
-            block = self.batches.relation.take(self.batches.batch_rows[i])
-            part = self.eval_fn(block, self.padded)
+            batch_rows = self.batches.batch_rows[i]
+            with TraceAnnotation("verdict.gather", rows=len(batch_rows)):
+                block = self.batches.relation.take(batch_rows)
+            with TraceAnnotation("verdict.scan"):
+                part = self.eval_fn(block, self.padded)
             self._snapshots.append(
                 part if not self._snapshots else self._snapshots[-1] + part
             )
             if self.stats is not None:
                 self.stats.eval_calls += 1
                 self.stats.batches_scanned += 1
-                self.stats.tuples_scanned += len(self.batches.batch_rows[i])
+                self.stats.tuples_scanned += len(batch_rows)
         if b not in self._estimates:
-            theta, beta2, _ = estimates_from_partials(
-                self._snapshots[b], self.padded
-            )
+            with TraceAnnotation("verdict.estimates"):
+                theta, beta2, _ = estimates_from_partials(
+                    self._snapshots[b], self.padded
+                )
             self._estimates[b] = (theta, beta2)
         theta, beta2 = self._estimates[b]
         if rows is None:
@@ -396,9 +401,10 @@ def replay_rounds(
         used = b + 1
         health: Dict[str, str] = {}
         if cfg.learning:
-            improved = engine.store.improve_groups(
-                lp.plan.snippets, raw, use_kernels=cfg.use_kernels,
-                health=health)
+            with TraceAnnotation("verdict.improve"):
+                improved = engine.store.improve_groups(
+                    lp.plan.snippets, raw, use_kernels=cfg.use_kernels,
+                    health=health)
         else:
             improved = ImprovedAnswer(
                 raw.theta, raw.beta2, raw.theta, raw.beta2,

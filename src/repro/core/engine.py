@@ -34,6 +34,7 @@ import warnings
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.aqp import queries as Q
 from repro.aqp.executor import ScanPlacement, eval_partials
@@ -205,7 +206,8 @@ class VerdictEngine:
         sample batch, instead of one eval (and one ``relation.take``) per
         query. Mask columns are computed independently per snippet, so the
         per-query booleans — and hence the discovered groups — are identical
-        to the one-probe-at-a-time path.
+        to the one-probe-at-a-time path. The probe, with its (T, Q) mask
+        pull to the host, is the ``verdict.discover`` span.
         """
         out: List[Optional[tuple]] = [None] * len(queries)
         need = []
@@ -218,31 +220,32 @@ class VerdictEngine:
             return out
         from repro.aqp.executor import predicate_mask
 
-        first = self.batches.relation.take(self.batches.batch_rows[0])
-        plans = [
-            Q.decompose(
-                self.schema,
-                Q.AggQuery(aggs=(Q.AggSpec("COUNT"),),
-                           predicates=queries[i].predicates),
-            )
-            for i in need
-        ]
-        fused = SnippetBatch.concat([p.snippets for p in plans])
-        mask_all = np.asarray(
-            predicate_mask(first.num_normalized, first.cat, pad_snippets(fused))
-        ).astype(bool)
-        cat_first = np.asarray(first.cat)
-        off = 0
-        for i, plan in zip(need, plans):
-            q = queries[i]
-            mask = mask_all[:, off]
-            off += plan.snippets.n
-            cats = cat_first[mask][:, list(q.groupby)]
-            if cats.size == 0:
-                out[i] = tuple()
-                continue
-            uniq = np.unique(cats, axis=0)
-            out[i] = tuple(tuple(int(v) for v in row) for row in uniq)
+        with TraceAnnotation("verdict.discover"):
+            first = self.batches.relation.take(self.batches.batch_rows[0])
+            plans = [
+                Q.decompose(
+                    self.schema,
+                    Q.AggQuery(aggs=(Q.AggSpec("COUNT"),),
+                               predicates=queries[i].predicates),
+                )
+                for i in need
+            ]
+            fused = SnippetBatch.concat([p.snippets for p in plans])
+            mask_all = np.asarray(predicate_mask(
+                first.num_normalized, first.cat, pad_snippets(fused)
+            )).astype(bool)
+            cat_first = np.asarray(first.cat)
+            off = 0
+            for i, plan in zip(need, plans):
+                q = queries[i]
+                mask = mask_all[:, off]
+                off += plan.snippets.n
+                cats = cat_first[mask][:, list(q.groupby)]
+                if cats.size == 0:
+                    out[i] = tuple()
+                    continue
+                uniq = np.unique(cats, axis=0)
+                out[i] = tuple(tuple(int(v) for v in row) for row in uniq)
         return out
 
     # ------------------------------------------------------------- execute

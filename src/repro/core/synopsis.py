@@ -48,6 +48,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import covariance, inference, learning, validation
 from repro.ft import faults
@@ -476,7 +477,7 @@ class Synopsis:
                 self._unapplied.append(item)
                 return
         try:
-            with self._on_host():
+            with self._on_host(), TraceAnnotation("verdict.ingest"):
                 self._apply_add(*item)
         except BaseException as e:  # noqa: BLE001 — quarantine, never raise
             self._mark_quarantined(e, item)

@@ -25,6 +25,13 @@ real answer. A ``finally`` backstop guarantees no ticket ever hangs, even if
 the isolation machinery itself dies. Per-query wall-clock ``deadline_s``
 (threaded from ``ErrorBudget.deadline_s``) bounds response time: on expiry
 the best-so-far answer returns with its honest wider CI.
+
+Observability: cumulative counters (``counters()``: flushed queries, the
+time tickets waited in the queue, prescreen lookups and the time they waited
+for the engine lock, retries, bisects) and host spans on the profiler's
+clock (``verdict.prescreen``, ``verdict.flush``, ``verdict.resolve``), which
+record only while a ``jax.profiler`` session is active. Durations come from
+the injected ``clock``, so tests can script them.
 """
 from __future__ import annotations
 
@@ -32,6 +39,8 @@ import dataclasses
 import threading
 import time
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.aqp.batch import BatchExecutor, BatchStats
 from repro.aqp.queries import AggQuery
@@ -45,9 +54,13 @@ class Ticket:
     The result is stored on the ticket itself, so a long-lived service
     retains nothing once callers drop their tickets. ``resolutions`` counts
     resolve calls — the exactly-once contract the concurrency tests pin.
+    ``req`` is the front's request id (joins the request's spans with the
+    flush that answered it); ``_enqueued`` the service clock at enqueue.
     """
 
     _service: "AqpService"
+    req: Optional[int] = None
+    _enqueued: float = 0.0
     _result: object = None
     _done: bool = False
     resolutions: int = 0
@@ -86,7 +99,9 @@ class AqpService:
     per-tenant counters; ``engine_lock``: pass one lock to every service
     sharing an engine (shared-store tenancy) so engine execution — and the
     intel prescreen that mutates shared cache state — serializes across
-    them while isolated engines keep scanning in parallel.
+    them while isolated engines keep scanning in parallel; ``clock``: the
+    monotonic time source of the wait counters (inject a fake to script
+    them).
     """
 
     def __init__(self, engine, max_batch: int = 64,
@@ -99,7 +114,8 @@ class AqpService:
                  backoff_base_s: float = 0.01,
                  backoff_max_s: float = 0.5,
                  tenant: Optional[str] = None,
-                 engine_lock: Optional[threading.Lock] = None):
+                 engine_lock: Optional[threading.Lock] = None,
+                 clock=time.monotonic):
         # Accept either a raw VerdictEngine or a repro.verdict Session.
         self.engine = getattr(engine, "engine", engine)
         self.max_batch = int(max_batch)
@@ -128,10 +144,20 @@ class AqpService:
         # at a time; shared across services when their engines are shared.
         self._exec_lock = engine_lock if engine_lock is not None \
             else threading.Lock()
+        self._clock = clock
         self.flushes = 0
         # Queries resolved at submit() by the workload-intelligence answer
         # cache (repro.intel) — they never entered a microbatch.
         self.prescreened = 0
+        # Cumulative, under ``_lock``: tickets taken by flushes and their
+        # summed enqueue -> queue-swap wait; prescreen lookups and their
+        # summed wait for the engine lock; slice retries and bisections.
+        self.flushed_queries = 0
+        self.queue_wait_s = 0.0
+        self.prescreen_lookups = 0
+        self.prescreen_lock_wait_s = 0.0
+        self.retries = 0
+        self.bisects = 0
         self.last_stats: Optional[BatchStats] = None
 
     @property
@@ -139,17 +165,18 @@ class AqpService:
         """Queries waiting for the next flush."""
         return len(self._queue)
 
-    def submit(self, query: AggQuery) -> Ticket:
+    def submit(self, query: AggQuery, req: Optional[int] = None) -> Ticket:
         """Enqueue one query; auto-flushes when the microbatch is full.
 
         Accepts an ``AggQuery`` or anything with ``.build()`` (the facade's
         ``QueryBuilder``). Thread-safe: the append and the threshold check
         happen under one lock, so concurrent submitters can neither lose an
-        entry nor double-flush the same batch.
+        entry nor double-flush the same batch. ``req``: the caller's request
+        id, carried on the ticket and into the spans.
         """
         if not isinstance(query, AggQuery) and hasattr(query, "build"):
             query = query.build()
-        ticket = Ticket(self)
+        ticket = Ticket(self, req=req)
         # Workload-intelligence pre-screen: a semantic-cache hit resolves
         # the ticket immediately — it never occupies a microbatch slot, so
         # repeated dashboard queries stop forcing flush cycles at all. The
@@ -157,12 +184,18 @@ class AqpService:
         # engine lock like every other engine-state access.
         intel = getattr(self.engine, "intel", None)
         if intel is not None:
-            with self._exec_lock:
-                served = intel.lookup(
-                    self.engine, query,
-                    target_rel_error=self.target_rel_error,
-                    stop_delta=self.stop_delta, max_batches=self.max_batches,
-                    tenant=self.tenant)
+            with TraceAnnotation("verdict.prescreen", req=req):
+                t0 = self._clock()
+                with self._exec_lock:
+                    waited = self._clock() - t0
+                    served = intel.lookup(
+                        self.engine, query,
+                        target_rel_error=self.target_rel_error,
+                        stop_delta=self.stop_delta,
+                        max_batches=self.max_batches, tenant=self.tenant)
+            with self._lock:
+                self.prescreen_lookups += 1
+                self.prescreen_lock_wait_s += waited
             if served is not None:
                 if self.result_wrapper is not None:
                     served = self.result_wrapper(served)
@@ -171,6 +204,7 @@ class AqpService:
                 ticket._resolve(served)
                 return ticket
         with self._lock:
+            ticket._enqueued = self._clock()
             self._queue.append((query, ticket))
             full = len(self._queue) >= self.max_batch
         if full:
@@ -221,6 +255,8 @@ class AqpService:
             for attempt in range(retries):
                 time.sleep(min(self.backoff_base_s * 2 ** attempt,
                                self.backoff_max_s))
+                with self._lock:
+                    self.retries += 1
                 try:
                     out = run()
                     break
@@ -228,6 +264,8 @@ class AqpService:
                     e = retry_e
             if out is None:
                 if len(idxs) > 1:
+                    with self._lock:
+                        self.bisects += 1
                     mid = len(idxs) // 2
                     self._resolve(queries, idxs[:mid], results, counts,
                                   top=False)
@@ -255,17 +293,31 @@ class AqpService:
         with self._exec_lock:
             with self._lock:
                 batch, self._queue = self._queue, []
+                if batch:
+                    swap = self._clock()
+                    self.flushed_queries += len(batch)
+                    self.queue_wait_s += sum(swap - t._enqueued
+                                             for _, t in batch)
             if not batch:
                 return []
-            queries = [q for q, _ in batch]
-            results: List = [None] * len(batch)
-            counts: Dict[int, int] = {}
-            try:
-                self._resolve(queries, list(range(len(batch))), results,
-                              counts)
-            finally:
-                # Backstop: no ticket may ever hang or silently carry None,
-                # even if the isolation machinery itself raised.
+            # Space-separated: the profiler splits span arguments at commas.
+            reqs = " ".join(str(t.req) for _, t in batch if t.req is not None)
+            with TraceAnnotation("verdict.flush", flush=self.flushes + 1,
+                                 queries=len(batch), reqs=reqs):
+                out = self._flush_batch(batch)
+        return out
+
+    def _flush_batch(self, batch: List[tuple]) -> List:
+        """Resolve every ticket of one swapped-out batch (engine lock held)."""
+        queries = [q for q, _ in batch]
+        results: List = [None] * len(batch)
+        counts: Dict[int, int] = {}
+        try:
+            self._resolve(queries, list(range(len(batch))), results, counts)
+        finally:
+            # Backstop: no ticket may ever hang or silently carry None,
+            # even if the isolation machinery itself raised.
+            with TraceAnnotation("verdict.resolve"):
                 out = []
                 for (_, ticket), res in zip(batch, results):
                     if res is None:
@@ -317,6 +369,29 @@ class AqpService:
         """
         self.engine.save_synopses(manager, step)
 
+    def counters(self) -> dict:
+        """One consistent snapshot of the microbatching counters.
+
+        ``flushes``; ``pending`` (queued now); ``prescreened`` (served by
+        the answer cache at submit); ``flushed_queries`` (tickets taken by
+        flushes) and ``queue_wait_s`` (their summed wait from enqueue to the
+        queue swap); ``prescreen_lookups`` and ``prescreen_lock_wait_s``
+        (their summed wait for the engine lock); ``retries`` and
+        ``bisects`` of failed slices. All but ``pending`` are cumulative.
+        """
+        with self._lock:
+            return {
+                "flushes": self.flushes,
+                "pending": len(self._queue),
+                "prescreened": self.prescreened,
+                "flushed_queries": self.flushed_queries,
+                "queue_wait_s": self.queue_wait_s,
+                "prescreen_lookups": self.prescreen_lookups,
+                "prescreen_lock_wait_s": self.prescreen_lock_wait_s,
+                "retries": self.retries,
+                "bisects": self.bisects,
+            }
+
     def stats(self) -> dict:
         """Operator snapshot: store placement/occupancy/back-pressure plus
         this service's microbatching counters and serving health."""
@@ -326,9 +401,7 @@ class AqpService:
         return {
             "store": self.engine.store.stats(),
             "tenant": self.tenant,
-            "flushes": self.flushes,
-            "pending": self.pending,
-            "prescreened": self.prescreened,
+            **self.counters(),
             "health": {
                 "quarantined": self.engine.store.quarantined(),
                 "faults": faults.stats(),

@@ -27,6 +27,7 @@ replay admission decisions against a scripted clock.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Dict, Iterator, Optional
@@ -92,11 +93,7 @@ class Tenant:
             "isolation": self.spec.isolation,
             "admission": self.admission.stats(),
             "metrics": self.metrics.snapshot(),
-            "service": {
-                "flushes": svc.flushes,
-                "pending": svc.pending,
-                "prescreened": svc.prescreened,
-            },
+            "service": svc.counters(),
             "health": {
                 "quarantined": self.session.store.quarantined(),
             },
@@ -115,6 +112,10 @@ class ServingFront:
     ``clock``: the monotonic time source feeding admission and latency
     metrics (``time.monotonic`` by default). Inject a fake for
     deterministic admission replay.
+
+    Every request gets one id (``new_request_id``), assigned where it
+    enters (the HTTP handler, else ``execute``); it rides the ticket into
+    the flush, so a request's spans join the flush that answered it.
     """
 
     def __init__(self, relation, config: Optional[EngineConfig] = None,
@@ -130,6 +131,13 @@ class ServingFront:
         # One engine lock for ALL services over the shared engine: flushes
         # and prescreen lookups across shared tenants serialize here.
         self._shared_engine_lock = threading.Lock()
+        self._request_ids = itertools.count(1)
+        self._request_ids_lock = threading.Lock()
+
+    def new_request_id(self) -> int:
+        """A fresh request id (unique within this front)."""
+        with self._request_ids_lock:
+            return next(self._request_ids)
 
     # --------------------------------------------------------------- tenants
     def add_tenant(self, spec) -> Tenant:
@@ -175,7 +183,8 @@ class ServingFront:
             tenant.metrics.record_rejection(rejection)
         return rejection
 
-    def execute(self, name: str, query, budget: Optional[ErrorBudget] = None):
+    def execute(self, name: str, query, budget: Optional[ErrorBudget] = None,
+                req: Optional[int] = None):
         """Run one query for ``name``; returns an answer-ladder value.
 
         ``Rejection`` (admission refused — the query never executed),
@@ -184,15 +193,18 @@ class ServingFront:
         per-request ``budget``, the query rides the tenant's microbatch
         service (coalescing with concurrent submitters under the tenant's
         default budget); an explicit budget executes directly through the
-        tenant's session under the same engine lock.
+        tenant's session under the same engine lock. ``req``: the request id
+        the transport assigned (a fresh one when None).
         """
+        if req is None:
+            req = self.new_request_id()
         tenant = self.tenant(name)
         rejection = self._admit(tenant)
         if rejection is not None:
             return rejection
         t0 = self.clock()
         if budget is None:
-            ans = tenant.service.submit(query).result()
+            ans = tenant.service.submit(query, req=req).result()
         else:
             with tenant.service._exec_lock:
                 ans = tenant.session.execute(query, budget=budget)

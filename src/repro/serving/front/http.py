@@ -21,12 +21,18 @@ request was served; the outcome is in the body's ``kind``.
 
 Each request runs on its own thread (``ThreadingHTTPServer``), which is
 exactly the concurrency the front's admission + engine-lock design expects.
+Each POST takes one request id from the front and opens a
+``verdict.request`` span (and ``verdict.encode`` around the reply) on the
+profiler's clock; spans record only while a ``jax.profiler`` session is
+active.
 """
 from __future__ import annotations
 
 import json
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+
+from jax.profiler import TraceAnnotation
 
 from repro.serving.front.wire import (
     WireError,
@@ -119,6 +125,11 @@ class _Handler(BaseHTTPRequestHandler):
         if verb not in ("execute", "explain", "stream") or tenant is None:
             self._error(404, f"no such route: POST {self.path}")
             return
+        req = self.front.new_request_id()
+        with TraceAnnotation("verdict.request", req=req):
+            self._post(verb, tenant, req)
+
+    def _post(self, verb: str, tenant: str, req: int):
         try:
             body = self._read_body()
             query = query_from_json(self._schema(tenant), body.get("query"))
@@ -133,17 +144,18 @@ class _Handler(BaseHTTPRequestHandler):
             self._stream(tenant, query, budget)
             return
         if verb == "execute":
-            ans = self.front.execute(tenant, query, budget=budget)
+            ans = self.front.execute(tenant, query, budget=budget, req=req)
         else:
             ans = self.front.explain(tenant, query, budget=budget)
-        if getattr(ans, "rejected", False):
-            self._send_json(
-                ans.status, answer_to_json(ans),
-                headers=[("Retry-After", f"{ans.retry_after_s:.3f}")])
-        elif verb == "explain":
-            self._send_json(200, report_to_json(ans))
-        else:
-            self._send_json(200, answer_to_json(ans))
+        with TraceAnnotation("verdict.encode", req=req):
+            if getattr(ans, "rejected", False):
+                self._send_json(
+                    ans.status, answer_to_json(ans),
+                    headers=[("Retry-After", f"{ans.retry_after_s:.3f}")])
+            elif verb == "explain":
+                self._send_json(200, report_to_json(ans))
+            else:
+                self._send_json(200, answer_to_json(ans))
 
     def _schema(self, tenant: str):
         return self.front.tenant(tenant).session.schema

@@ -140,6 +140,9 @@ def test_persistent_scan_fault_typed_failure_no_hung_tickets(relation):
         # bound): full batch of 4 (1 + max_retries backoff retry), its
         # bisected half of 2, then the single (1 + max_retries).
         assert ans.attempts == 5
+    # One retry of the whole batch and one of each single; the batch, then
+    # each half, bisected.
+    assert (svc.counters()["retries"], svc.counters()["bisects"]) == (5, 3)
     # The service stays usable after the chaos clears.
     ok = svc.submit(_queries(session)[1])
     svc.flush()

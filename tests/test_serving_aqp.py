@@ -209,6 +209,7 @@ def test_transient_fault_retries_whole_slice_before_bisecting(relation,
     with faults.inject(faults.FaultSpec("scan.eval", hits=(0,))):
         svc.flush()
     assert calls == [4, 4]  # full slice, failed; full slice again, clean
+    assert (svc.counters()["retries"], svc.counters()["bisects"]) == (1, 0)
     for t in tickets:
         ans = t.result()
         assert not getattr(ans, "failed", False)
@@ -237,3 +238,27 @@ def test_deadline_degraded_flush_never_primes_the_answer_cache(relation):
     second = second_ticket.result()
     assert second.degraded and second.served_from is None
     assert session.stats()["intel"]["insertions"] == 0
+
+
+def test_wait_counters_from_a_scripted_clock(relation, workload):
+    """Three submits, one flush: the flushed queries, their summed queue
+    wait and the prescreens' summed engine-lock wait, exactly, from the
+    times the service's clock gave (before the lock, after it, at enqueue;
+    then the flush's queue swap)."""
+    times = iter([0.0, 0.25, 0.5,
+                  1.0, 1.5, 2.0,
+                  3.0, 3.0, 3.5,
+                  5.0])
+    session = vd.connect(relation, _cfg(), cache=True)
+    svc = AqpService(session, max_batch=8, clock=lambda: next(times))
+    tickets = [svc.submit(q) for q in workload[:3]]
+    assert svc.counters()["flushed_queries"] == 0
+    svc.flush()
+    assert all(t._done for t in tickets)
+    c = svc.counters()
+    assert (c["flushes"], c["flushed_queries"], c["pending"]) == (1, 3, 0)
+    assert c["queue_wait_s"] == (5.0 - 0.5) + (5.0 - 2.0) + (5.0 - 3.5)
+    assert c["prescreen_lookups"] == 3
+    assert c["prescreen_lock_wait_s"] == 0.25 + 0.5 + 0.0
+    assert (c["retries"], c["bisects"], c["prescreened"]) == (0, 0, 0)
+    assert {k: svc.stats()[k] for k in c} == c
