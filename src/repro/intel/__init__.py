@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import threading
 from typing import Optional, Tuple
 
 import numpy as np
@@ -58,12 +59,27 @@ class IntelConfig:
 class WorkloadIntel:
     """The workload-intelligence plane of one engine (cache+router+counters).
 
-    Single-threaded like the engine's serve path; attach one instance per
-    engine (``repro.verdict.connect(cache=True)``).
+    Attach one instance per engine (``repro.verdict.connect(cache=True)``).
+    Thread-safe: every public method takes ``lock``, the plane's own
+    re-entrant lock, so a serving front's prescreen ``lookup`` runs beside a
+    flush's ``choose_route``/``observe`` without holding the engine lock
+    (``repro.serving.aqp``). Lock order: engine lock, then ``lock`` — a
+    flush, ``explain`` or ``stream`` reaches here with the engine lock held,
+    a prescreen takes only ``lock``, and nothing here takes the engine
+    lock, so there is no cycle. ``lock`` is re-entrant so a caller can hold
+    it around a call to time its wait (the microbatcher's prescreen).
+
+    A lookup outside the engine lock is sound: the cache reads the store
+    only through ``store.get`` and ``store.generation``, single attribute
+    reads whose generations are bumped synchronously (ingest threads run
+    beside lookups already). A lookup that races a flush between its
+    ``store.record`` and its ``observe`` behaves as if it ran just before
+    that flush: it misses, or refuses the entry as stale, and executes.
     """
 
     def __init__(self, config: Optional[IntelConfig] = None):
         self.config = config or IntelConfig()
+        self.lock = threading.RLock()
         self.telemetry = IntelTelemetry()
         self.cache = AnswerCache(capacity=self.config.capacity,
                                  subsumption=self.config.subsumption)
@@ -88,21 +104,22 @@ class WorkloadIntel:
         namespace) — counted in ``telemetry.per_tenant`` so a shared cache
         still reports per-tenant hit rates."""
         sig = QuerySignature.from_query(engine.schema, query)
-        if sig is None:
-            self.telemetry.lookups += 1
-            self.telemetry.misses += 1
-            self.telemetry.uncacheable += 1
+        with self.lock:
+            if sig is None:
+                self.telemetry.lookups += 1
+                self.telemetry.misses += 1
+                self.telemetry.uncacheable += 1
+                if tenant is not None:
+                    self.telemetry.record_tenant(tenant, hit=False)
+                return None
+            delta, eff = self._budget(engine, stop_delta, max_batches)
+            res = self.cache.lookup(engine.store, sig, target_rel_error,
+                                    delta, eff, telemetry=self.telemetry)
+            if res is not None:
+                self.telemetry.record_route("cache")
             if tenant is not None:
-                self.telemetry.record_tenant(tenant, hit=False)
-            return None
-        delta, eff = self._budget(engine, stop_delta, max_batches)
-        res = self.cache.lookup(engine.store, sig, target_rel_error, delta,
-                                eff, telemetry=self.telemetry)
-        if res is not None:
-            self.telemetry.record_route("cache")
-        if tenant is not None:
-            self.telemetry.record_tenant(tenant, hit=res is not None)
-        return res
+                self.telemetry.record_tenant(tenant, hit=res is not None)
+            return res
 
     def peek(self, engine, query, target_rel_error: Optional[float] = None,
              stop_delta: Optional[float] = None,
@@ -114,21 +131,24 @@ class WorkloadIntel:
         if sig is None:
             return "uncacheable", "scan"
         delta, eff = self._budget(engine, stop_delta, max_batches)
-        res = self.cache.lookup(engine.store, sig, target_rel_error, delta,
-                                eff, mutate=False)
-        if res is not None:
-            status = ("exact" if res.served_from == "cache:exact"
-                      else "subsumed")
-            return status, "cache"
-        if lp is None or not lp.supported or lp.plan is None:
-            return "miss", "scan" if target_rel_error is None else "improve"
-        return "miss", self.router.predict_route(
-            engine, lp, target_rel_error, eff)
+        with self.lock:
+            res = self.cache.lookup(engine.store, sig, target_rel_error,
+                                    delta, eff, mutate=False)
+            if res is not None:
+                status = ("exact" if res.served_from == "cache:exact"
+                          else "subsumed")
+                return status, "cache"
+            if lp is None or not lp.supported or lp.plan is None:
+                return ("miss",
+                        "scan" if target_rel_error is None else "improve")
+            return "miss", self.router.predict_route(
+                engine, lp, target_rel_error, eff)
 
     def choose_route(self, engine, lp, target_rel_error: Optional[float],
                      max_batches: int) -> str:
-        return self.router.choose_route(engine, lp, target_rel_error,
-                                        max_batches)
+        with self.lock:
+            return self.router.choose_route(engine, lp, target_rel_error,
+                                            max_batches)
 
     def occupancy(self, engine, lp):
         """The store occupancy ``lp`` is served from (see
@@ -136,7 +156,8 @@ class WorkloadIntel:
         observes."""
         if not lp.supported or lp.plan is None:
             return None
-        return self.router.occupancy(engine, lp)
+        with self.lock:
+            return self.router.occupancy(engine, lp)
 
     def observe(self, engine, lp, res, target_rel_error: Optional[float],
                 max_batches: int, route: str, occupancy):
@@ -146,8 +167,10 @@ class WorkloadIntel:
         lifecycle, so the generation snapshot includes the answer's own
         ingest bump; ``occupancy`` (``self.occupancy``) was read before
         that record."""
-        self.telemetry.record_route(route)
-        if lp.supported:
+        with self.lock:
+            self.telemetry.record_route(route)
+            if not lp.supported:
+                return
             self.router.observe(engine, lp, res, target_rel_error, route,
                                 occupancy)
             sig = QuerySignature.from_query(engine.schema, lp.query)
@@ -160,26 +183,29 @@ class WorkloadIntel:
     def state_dict(self, store) -> dict:
         """One ``"blob"`` uint8 array (canonical JSON) — rides the same
         np.savez + CRC checkpoint payload the synopses use."""
-        payload = {
-            "cache": self.cache.state_dict(store),
-            "router": self.router.state_dict(),
-            "telemetry": self.telemetry.state_dict(),
-        }
+        with self.lock:
+            payload = {
+                "cache": self.cache.state_dict(store),
+                "router": self.router.state_dict(),
+                "telemetry": self.telemetry.state_dict(),
+            }
         raw = json.dumps(payload, sort_keys=True).encode()
         return {"blob": np.frombuffer(raw, dtype=np.uint8)}
 
     def load_state_dict(self, state: dict, store):
         blob = np.asarray(state["blob"], dtype=np.uint8)
         payload = json.loads(bytes(blob).decode())
-        self.cache.load_state_dict(payload.get("cache", {}), store)
-        self.router.load_state_dict(payload.get("router", {}))
-        self.telemetry.load_state_dict(payload.get("telemetry", {}))
+        with self.lock:
+            self.cache.load_state_dict(payload.get("cache", {}), store)
+            self.router.load_state_dict(payload.get("router", {}))
+            self.telemetry.load_state_dict(payload.get("telemetry", {}))
 
     # ---------------------------------------------------------------- stats
     def stats(self) -> dict:
-        out = self.telemetry.as_dict()
-        out["enabled"] = True
-        out["entries"] = len(self.cache)
-        out["capacity"] = self.cache.capacity
-        out["router"] = self.router.stats()
+        with self.lock:
+            out = self.telemetry.as_dict()
+            out["enabled"] = True
+            out["entries"] = len(self.cache)
+            out["capacity"] = self.cache.capacity
+            out["router"] = self.router.stats()
         return out

@@ -13,7 +13,11 @@ lock, the engine itself is driven under a separate execution lock (pass
 ``engine_lock=`` to share it between services whose engines share learned
 state — the multi-tenant front does), and every ticket carries an event so
 ``Ticket.result()`` from one thread waits correctly for a flush running on
-another. Every ticket resolves exactly once (``Ticket.resolutions``).
+another. Every ticket resolves exactly once (``Ticket.resolutions``). The
+answer-cache prescreen at ``submit`` takes only the cache's own lock
+(``WorkloadIntel.lock``), never the execution lock, so arrivals enqueue
+while a flush runs and the next flush takes all of them; a ``result()``
+whose ticket that flush already answered returns without leading another.
 
 Fault isolation (the serving half of the degraded-mode contract): one poison
 query can no longer strand its microbatch. ``flush`` retries a failed fused
@@ -28,7 +32,7 @@ the best-so-far answer returns with its honest wider CI.
 
 Observability: cumulative counters (``counters()``: flushed queries, the
 time tickets waited in the queue, prescreen lookups and the time they waited
-for the engine lock, retries, bisects) and host spans on the profiler's
+for the cache's lock, retries, bisects) and host spans on the profiler's
 clock (``verdict.prescreen``, ``verdict.flush``, ``verdict.resolve``), which
 record only while a ``jax.profiler`` session is active. Durations come from
 the injected ``clock``, so tests can script them.
@@ -71,11 +75,13 @@ class Ticket:
         """The query's ``QueryResult`` (flushes the queue if still pending).
 
         Safe under concurrency: if another thread's flush owns this ticket's
-        batch, the local ``flush()`` finds an empty queue and this call
-        waits on the ticket's event instead of returning a premature None.
+        batch, the local flush finds an empty queue and this call waits on
+        the ticket's event instead of returning a premature None; if that
+        flush already answered the ticket by the time this call holds the
+        engine lock, it returns without flushing the newer arrivals.
         """
         if not self._done:
-            self._service.flush()
+            self._service.flush(leader=self)
         if not self._event.wait(timeout):
             raise TimeoutError("ticket unresolved after "
                                f"{timeout}s (flush still in flight?)")
@@ -97,9 +103,9 @@ class AqpService:
     ``mesh``: optional device mesh for the sharded scan path;
     ``tenant``: optional tenant label threaded into the workload-intel
     per-tenant counters; ``engine_lock``: pass one lock to every service
-    sharing an engine (shared-store tenancy) so engine execution — and the
-    intel prescreen that mutates shared cache state — serializes across
-    them while isolated engines keep scanning in parallel; ``clock``: the
+    sharing an engine (shared-store tenancy) so engine execution serializes
+    across them while isolated engines keep scanning in parallel (the
+    intel prescreen serializes on the shared cache's own lock); ``clock``: the
     monotonic time source of the wait counters (inject a fake to script
     them).
     """
@@ -140,8 +146,8 @@ class AqpService:
         self._queue: List[tuple] = []  # (query, ticket) pairs
         # Queue/counter bookkeeping lock (never held across an engine call).
         self._lock = threading.Lock()
-        # Engine execution lock: one flush (or prescreen) drives the engine
-        # at a time; shared across services when their engines are shared.
+        # Engine execution lock: one flush drives the engine at a time;
+        # shared across services when their engines are shared.
         self._exec_lock = engine_lock if engine_lock is not None \
             else threading.Lock()
         self._clock = clock
@@ -151,11 +157,14 @@ class AqpService:
         self.prescreened = 0
         # Cumulative, under ``_lock``: tickets taken by flushes and their
         # summed enqueue -> queue-swap wait; prescreen lookups and their
-        # summed wait for the engine lock; slice retries and bisections.
+        # summed wait for the cache's lock; result() calls that found their
+        # ticket answered once they held the engine lock; slice retries and
+        # bisections.
         self.flushed_queries = 0
         self.queue_wait_s = 0.0
         self.prescreen_lookups = 0
         self.prescreen_lock_wait_s = 0.0
+        self.result_found_resolved = 0
         self.retries = 0
         self.bisects = 0
         self.last_stats: Optional[BatchStats] = None
@@ -180,13 +189,14 @@ class AqpService:
         # Workload-intelligence pre-screen: a semantic-cache hit resolves
         # the ticket immediately — it never occupies a microbatch slot, so
         # repeated dashboard queries stop forcing flush cycles at all. The
-        # lookup mutates shared LRU/counter state, so it runs under the
-        # engine lock like every other engine-state access.
+        # lookup mutates shared LRU/counter state under the cache's own
+        # lock, not the engine lock, so it never waits for a running flush
+        # (``WorkloadIntel`` says why reading the store beside one is sound).
         intel = getattr(self.engine, "intel", None)
         if intel is not None:
             with TraceAnnotation("verdict.prescreen", req=req):
                 t0 = self._clock()
-                with self._exec_lock:
+                with intel.lock:
                     waited = self._clock() - t0
                     served = intel.lookup(
                         self.engine, query,
@@ -279,7 +289,7 @@ class AqpService:
         for i, r in zip(idxs, out):
             results[i] = r
 
-    def flush(self) -> List:
+    def flush(self, leader: Optional[Ticket] = None) -> List:
         """Execute all pending queries in one fused scan.
 
         Every ticket RESOLVES, unconditionally and exactly once: to its
@@ -289,8 +299,17 @@ class AqpService:
         isolation only engages on failure. Concurrent flushes serialize on
         the engine lock; the queue swap is atomic, so two racing flushes
         split the pending work instead of double-executing it.
+
+        ``leader``: the ticket whose ``result()`` asked for this flush. If
+        another thread's flush answered it while this call waited for the
+        engine lock, return ``[]`` at once and leave the newer arrivals
+        queued for the callers still waiting on them.
         """
         with self._exec_lock:
+            if leader is not None and leader._done:
+                with self._lock:
+                    self.result_found_resolved += 1
+                return []
             with self._lock:
                 batch, self._queue = self._queue, []
                 if batch:
@@ -376,7 +395,9 @@ class AqpService:
         the answer cache at submit); ``flushed_queries`` (tickets taken by
         flushes) and ``queue_wait_s`` (their summed wait from enqueue to the
         queue swap); ``prescreen_lookups`` and ``prescreen_lock_wait_s``
-        (their summed wait for the engine lock); ``retries`` and
+        (their summed wait for the cache's lock); ``result_found_resolved``
+        (``result()`` calls whose ticket another flush had answered by the
+        time they held the engine lock, so they led none); ``retries`` and
         ``bisects`` of failed slices. All but ``pending`` are cumulative.
         """
         with self._lock:
@@ -388,6 +409,7 @@ class AqpService:
                 "queue_wait_s": self.queue_wait_s,
                 "prescreen_lookups": self.prescreen_lookups,
                 "prescreen_lock_wait_s": self.prescreen_lock_wait_s,
+                "result_found_resolved": self.result_found_resolved,
                 "retries": self.retries,
                 "bisects": self.bisects,
             }

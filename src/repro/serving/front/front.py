@@ -6,8 +6,9 @@ real multi-tenant server. Each ``TenantSpec`` declares its isolation mode:
 - ``"shared"`` tenants attach to ONE engine (``Session.attached``), so they
   read and write the same ``SynopsisStore`` and ``WorkloadIntel`` namespace
   — a query learned by tenant A makes tenant B's next repeat cheaper. All
-  shared services serialize on one engine lock; the workload-intel plane
-  still splits hit-rates per tenant (``IntelTelemetry.per_tenant``).
+  shared services serialize their flushes on one engine lock; the
+  workload-intel plane, under its own lock, still splits hit-rates per
+  tenant (``IntelTelemetry.per_tenant``).
 - ``"isolated"`` tenants get their own engine/Session: private learned
   state, private answer cache, and scans that run in parallel with every
   other tenant.
@@ -129,7 +130,8 @@ class ServingFront:
         self._lock = threading.Lock()
         self._shared_session: Optional[Session] = None
         # One engine lock for ALL services over the shared engine: flushes
-        # and prescreen lookups across shared tenants serialize here.
+        # across shared tenants serialize here. Prescreen lookups take only
+        # the shared cache's own lock, so a hit never waits for a flush.
         self._shared_engine_lock = threading.Lock()
         self._request_ids = itertools.count(1)
         self._request_ids_lock = threading.Lock()

@@ -13,6 +13,9 @@ The binding contract is the bitwise oracle:
   the most refined full-budget answer, bitwise-equal to the always-improve
   engine when neither meets the target).
 """
+import sys
+import threading
+
 import numpy as np
 import pytest
 
@@ -385,6 +388,62 @@ def test_learned_ladder_floors_are_answer_invariant(relation):
     # still bitwise-equal to the static-floor engine.
     fresh = s.query().sum("v0").where(vd.between("x1", 2.0, 7.0)).build()
     assert _cells(s.execute(fresh, B)) == _cells(plain.execute(fresh, B))
+
+
+def test_intel_lock_guards_lookups_against_concurrent_inserts(relation):
+    """The prescreen's lookups run beside a flush's ``observe`` without the
+    engine lock: 4 threads look up (subsumption on, full cache, so each
+    lookup walks every entry) while another inserts past capacity. Without
+    the plane's lock the walk can raise ``OrderedDict mutated during
+    iteration`` and the counters' ``+=`` can lose updates."""
+    s = vd.connect(relation, _cfg(), cache=IntelConfig(capacity=16))
+    intel, eng = s.intel, s.engine
+    qs = [s.query().avg("v0").where(vd.between("x0", 1.0 + 0.2 * i, 9.5))
+          .build() for i in range(32)]
+    res = eng.execute(qs[0])
+    lps = plan_workload(eng, qs).logical
+    occ = [intel.occupancy(eng, lp) for lp in lps]
+    for k in range(16):
+        intel.observe(eng, lps[k], res, None, 4, "scan", occ[k])
+    assert intel.stats()["entries"] == 16
+    probe = s.query().avg("v0").where(vd.between("x0", 0.5, 9.0)).build()
+    n_lookups, n_threads = 200, 4
+    before = intel.telemetry.lookups
+    errors = []
+    start = threading.Barrier(n_threads + 1)
+
+    def looker():
+        start.wait()
+        try:
+            for _ in range(n_lookups):
+                assert intel.lookup(eng, probe) is None
+        except BaseException as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    def inserter():
+        start.wait()
+        try:
+            for k in range(320):  # 32 keys round an LRU of 16: all evict
+                intel.observe(eng, lps[k % 32], res, None, 4, "scan",
+                              occ[k % 32])
+        except BaseException as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    threads = [threading.Thread(target=looker) for _ in range(n_threads)]
+    threads.append(threading.Thread(target=inserter))
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)  # switch threads often: races show
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert errors == []
+    assert intel.telemetry.lookups - before == n_threads * n_lookups
+    assert intel.stats()["entries"] == 16
 
 
 # --------------------------------------------------------- serving surface

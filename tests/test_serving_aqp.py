@@ -242,9 +242,9 @@ def test_deadline_degraded_flush_never_primes_the_answer_cache(relation):
 
 def test_wait_counters_from_a_scripted_clock(relation, workload):
     """Three submits, one flush: the flushed queries, their summed queue
-    wait and the prescreens' summed engine-lock wait, exactly, from the
-    times the service's clock gave (before the lock, after it, at enqueue;
-    then the flush's queue swap)."""
+    wait and the prescreens' summed wait for the cache's lock, exactly, from
+    the times the service's clock gave (before the lock, after it, at
+    enqueue; then the flush's queue swap)."""
     times = iter([0.0, 0.25, 0.5,
                   1.0, 1.5, 2.0,
                   3.0, 3.0, 3.5,
@@ -262,3 +262,101 @@ def test_wait_counters_from_a_scripted_clock(relation, workload):
     assert c["prescreen_lock_wait_s"] == 0.25 + 0.5 + 0.0
     assert (c["retries"], c["bisects"], c["prescreened"]) == (0, 0, 0)
     assert {k: svc.stats()[k] for k in c} == c
+
+
+class _CountedLock:
+    """An engine lock that counts each caller before it may block."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._asked = threading.Condition()
+        self.asks = 0
+
+    def __enter__(self):
+        with self._asked:
+            self.asks += 1
+            self._asked.notify_all()
+        return self._lock.__enter__()
+
+    def __exit__(self, *exc):
+        return self._lock.__exit__(*exc)
+
+    def wait_for_asks(self, n: int) -> bool:
+        with self._asked:
+            return self._asked.wait_for(lambda: self.asks >= n, timeout=60)
+
+
+def test_arrivals_coalesce_while_a_flush_holds_the_engine_lock(relation,
+                                                               workload):
+    """The prescreen takes only the cache's lock, so while the engine lock
+    is held (as a running flush holds it) 8 callers each finish their
+    lookup and enqueue. On release one flush carries all 8: its leader
+    answers the other 7, whose ``result()`` then returns without a flush."""
+    lock = _CountedLock()
+    svc = AqpService(vd.connect(relation, _cfg(), cache=True), max_batch=64,
+                     engine_lock=lock)
+    tickets, answers = [None] * 8, [None] * 8
+
+    def caller(i):
+        tickets[i] = svc.submit(workload[i])
+        answers[i] = tickets[i].result(timeout=120)
+
+    threads = [threading.Thread(target=caller, args=(i,)) for i in range(8)]
+    with svc._exec_lock:
+        for t in threads:
+            t.start()
+        # Each caller asks for the engine lock in result(), after its
+        # submit: this one's ask plus 8.
+        assert lock.wait_for_asks(1 + 8)
+        c = svc.counters()
+        assert (c["prescreen_lookups"], c["pending"]) == (8, 8)
+        assert c["flushes"] == 0
+        index = {id(q): i for i, q in enumerate(workload[:8])}
+        order = [index[id(q)] for q, _ in svc._queue]
+    for t in threads:
+        t.join(timeout=300)
+    assert not any(t.is_alive() for t in threads)
+    c = svc.counters()
+    assert (c["flushes"], c["flushed_queries"], c["pending"]) == (1, 8, 0)
+    assert c["result_found_resolved"] == 7
+    assert all(t.resolutions == 1 for t in tickets)
+    assert all(a is not None and a.served_from is None for a in answers)
+    # The coalesced answers are those of the same batch flushed in one go.
+    twin = AqpService(vd.connect(relation, _cfg(), cache=True), max_batch=64)
+    assert [answers[i].cells for i in order] == [
+        r.cells for r in twin.execute([workload[i] for i in order])]
+
+
+def test_result_answered_by_another_flush_leads_none(relation, workload):
+    """A ``result()`` that waits for the engine lock while another thread's
+    flush answers its ticket returns without flushing the newer arrival,
+    and ``result_found_resolved`` counts it."""
+    lock = _CountedLock()
+    svc = AqpService(VerdictEngine(relation, _cfg()), max_batch=64,
+                     engine_lock=lock)
+    inner = svc._execute_slice
+    first = svc.submit(workload[0])
+    late, waiter = [], threading.Thread(target=first.result)
+
+    def slice_with_a_waiter(queries):
+        # Inside the flush that owns ``first``: another caller asks for it,
+        # and a new query arrives behind the running flush.
+        asks = lock.asks
+        waiter.start()
+        assert lock.wait_for_asks(asks + 1)
+        late.append(svc.submit(workload[1]))
+        return inner(queries)
+
+    svc._execute_slice = slice_with_a_waiter
+    svc.flush()
+    svc._execute_slice = inner
+    waiter.join(timeout=60)
+    assert not waiter.is_alive()
+    c = svc.counters()
+    assert (c["flushes"], c["pending"], c["result_found_resolved"]) == (
+        1, 1, 1)
+    assert first.resolutions == 1 and not late[0]._done
+    assert late[0].result() is not None
+    c = svc.counters()
+    assert (c["flushes"], c["pending"], c["result_found_resolved"]) == (
+        2, 0, 1)
